@@ -402,6 +402,8 @@ def main() -> int:
                    help="larger graphs (slower)")
     p.add_argument("--only", default=None)
     args = p.parse_args()
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     names = [args.only] if args.only else list(BENCHES)
     failures = 0

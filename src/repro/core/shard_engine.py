@@ -71,6 +71,7 @@ them (DESIGN.md §9).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import time
 from typing import Dict, Optional
@@ -79,7 +80,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import incom
 from repro import obs
@@ -91,10 +92,10 @@ from repro.graph.csr import CSRGraph, PartitionedCSR, ShardCSR, \
 AXIS = "shards"   # the walk-shard mesh / vmap axis name
 
 
-def make_walk_mesh(num_shards: int) -> Optional[Mesh]:
-    """A ("shards",)-mesh over local devices, or None when the host does
-    not have ``num_shards`` devices (callers then use the stacked
-    emulation, which is the same program under vmap)."""
+def make_walk_mesh(num_shards: int) -> Mesh:
+    """A ("shards",)-mesh over ``num_shards`` local devices. Raises when the
+    host has fewer; the stacked emulation (the same program under vmap) is
+    what ``run_walk_sharded`` runs when no mesh is passed."""
     from repro.dist.collectives import local_mesh
     return local_mesh(num_shards, AXIS)
 
@@ -713,20 +714,20 @@ def _run_stacked(graph, owner, sources, root_key, policy, spec, num_shards):
     return jax.vmap(per_shard, axis_name=AXIS)(jnp.arange(num_shards))
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("policy", "spec", "num_shards", "mesh"))
 def _run_spmd(graph, owner, sources, root_key, policy, spec,
               num_shards: int, mesh: Mesh):
-    from jax.experimental.shard_map import shard_map
-
     def per_shard(graph_, owner_, sources_, key_, _marker):
         out = _shard_program_replicated(graph_, owner_, sources_, key_,
                                         policy, spec)
         return jax.tree_util.tree_map(lambda x: x[None], out)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(), P(), P(), P(), P(AXIS)),
         out_specs=P(AXIS),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(graph, owner, sources, root_key, jnp.arange(num_shards))
 
@@ -745,22 +746,24 @@ def _run_stacked_local(slices, local_of, owner, sources, root_key,
     return jax.vmap(per_shard, axis_name=AXIS)(slices)
 
 
+@functools.partial(jax.jit,
+                   static_argnames=("policy", "spec", "num_shards", "mesh",
+                                    "pool", "cap", "compact_every",
+                                    "transport"))
 def _run_spmd_local(slices, local_of, owner, sources, root_key,
                     policy, spec, num_shards: int, mesh: Mesh,
                     pool: int, cap: int, compact_every: int, transport: str):
-    from jax.experimental.shard_map import shard_map
-
     def per_shard(slices_, local_of_, owner_, sources_, key_):
         out = _shard_program_local(
             slices_.take_shard(), local_of_, owner_, sources_, key_,
             policy, spec, num_shards, pool, cap, compact_every, transport)
         return jax.tree_util.tree_map(lambda x: x[None], out)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P(AXIS), P(), P(), P(), P()),
         out_specs=P(AXIS),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(slices, local_of, owner, sources, root_key)
 
@@ -885,7 +888,8 @@ _POOL_CACHE: Dict = {}
 
 def partitioned_csr_for(graph: CSRGraph, assignment: np.ndarray,
                         num_shards: int,
-                        key_obj: object = None) -> PartitionedCSR:
+                        key_obj: object = None,
+                        mesh: Optional[Mesh] = None) -> PartitionedCSR:
     """Memoized ``build_partitioned_csr`` — the slicing is host-side O(|E|)
     preprocessing and the engine is called once per walk batch per round.
 
@@ -899,17 +903,21 @@ def partitioned_csr_for(graph: CSRGraph, assignment: np.ndarray,
     VERSION (``graph.delta.graph_version``): a graph mutated through the
     delta overlay bumps its version, so an in-place edit of a held object
     can never be served the pre-mutation slices (identity alone would
-    silently alias them)."""
+    silently alias them). With ``mesh`` the slices are placed once, one
+    shard per device of the mesh, and cached placed."""
     import weakref
     from repro.graph.delta import graph_version
     key_obj = graph if key_obj is None else key_obj
     asn = np.asarray(assignment)
     key = (id(key_obj), graph_version(key_obj), num_shards,
-           graph.edge_cm is not None, hash(asn.tobytes()))
+           graph.edge_cm is not None, hash(asn.tobytes()), mesh)
     hit = _PCSR_CACHE.get(key)
     if hit is not None and hit[0]() is key_obj:
         return hit[1]
     pcsr = build_partitioned_csr(graph, asn, num_shards)
+    if mesh is not None:
+        pcsr = dataclasses.replace(pcsr, slices=jax.device_put(
+            pcsr.slices, NamedSharding(mesh, P(AXIS))))
     if len(_PCSR_CACHE) >= 8:
         _PCSR_CACHE.clear()
     _PCSR_CACHE[key] = (weakref.ref(key_obj), pcsr)
@@ -960,7 +968,11 @@ def run_walk_sharded(
     graph_key = graph          # caches key on the CALLER's (stable) object
     if getattr(policy, "needs_edge_cm", False) and graph.edge_cm is None:
         graph = graph.with_edge_cm()
-    use_mesh = mesh is not None and int(mesh.shape[AXIS]) == num_shards
+    if mesh is not None and int(mesh.shape[AXIS]) != num_shards:
+        raise ValueError(
+            f"mesh has {int(mesh.shape[AXIS])} {AXIS!r} devices for "
+            f"{num_shards} shards")
+    use_mesh = mesh is not None
     if engine == "auto":
         # Partition-local is the memory-correct engine when shards map to
         # real devices (each holds only its |V|/k + |E|/k slice). Under the
@@ -991,7 +1003,8 @@ def run_walk_sharded(
             "non-local CSR rows); use engine='replicated'")
 
     asn_np = np.asarray(assignment)
-    pcsr = partitioned_csr_for(graph, asn_np, num_shards, key_obj=graph_key)
+    pcsr = partitioned_csr_for(graph, asn_np, num_shards, key_obj=graph_key,
+                               mesh=mesh)
     b = int(sources.shape[0])
     init_occ = np.bincount(asn_np[np.asarray(sources)],
                            minlength=num_shards) if b else np.zeros(1)
@@ -1131,7 +1144,7 @@ def reconfigure_partitions(
                 if k[0] == id(key_obj) and k[-1] == h_old]:
         del _POOL_CACHE[key]
     new_key = (id(key_obj), gv, num_shards_new, graph.edge_cm is not None,
-               hash(new_asn.tobytes()))
+               hash(new_asn.tobytes()), None)
     if len(_PCSR_CACHE) >= 8:
         _PCSR_CACHE.clear()
     _PCSR_CACHE[new_key] = (weakref.ref(key_obj), new_pcsr)

@@ -17,7 +17,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -37,10 +36,10 @@ def hotness_sync_spmd(
         mean_out = jax.lax.pmean(po[r], axis)
         return pi.at[r].set(mean_in), po.at[r].set(mean_out)
 
-    pi2, po2 = shard_map(
+    pi2, po2 = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), P()), out_specs=(P(), P()),
-        check_rep=False,
+        check_vma=False,
     )(phi_in, phi_out, rows)
     dim = int(phi_in.shape[-1])
     nbytes = float(int(rows.shape[0]) * dim * 4 * m * 2)
@@ -182,14 +181,17 @@ def packed_all_to_all(
     return arrivals, arr_valid, sent
 
 
-def local_mesh(num_devices: int, axis: str) -> "Mesh | None":
-    """A 1-axis mesh over the first ``num_devices`` local devices, or None
-    when the host has fewer (callers fall back to a stacked vmap emulation
-    of the same program)."""
+def local_mesh(num_devices: int, axis: str) -> Mesh:
+    """A 1-axis mesh over the first ``num_devices`` local devices. Raises
+    when the host has fewer: a caller that asked for a mesh must never get
+    the stacked one-device emulation in its place without knowing."""
     import numpy as np
     devs = jax.devices()
     if len(devs) < num_devices:
-        return None
+        raise RuntimeError(
+            f"a {num_devices}-device {axis!r} mesh needs {num_devices} "
+            f"devices; this host has {len(devs)} "
+            f"({devs[0].platform if devs else 'none'})")
     return Mesh(np.asarray(devs[:num_devices]), (axis,))
 
 

@@ -16,7 +16,6 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -61,7 +60,7 @@ def pipeline_apply(
         # Only the last stage wrote non-zeros; psum replicates its stream.
         return jax.lax.psum(outs, axis)
 
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh, in_specs=(P(axis), P()), out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(stage_params, xs)

@@ -1,7 +1,8 @@
 """Jit'd wrapper around the fused SGNS Pallas kernel.
 
 Handles the time-axis padding the kernel wants (T -> T + 2w so windows are
-pure dynamic_slices) and exposes the same call signature as the pure-jnp
+plain ref slices; validity as a (T + 2w, 1) column so a window's mask is a
+sublane slice too) and exposes the same call signature as the pure-jnp
 reference (``ref.sgns_lifetime_batch_ref``).
 """
 
@@ -34,7 +35,8 @@ def sgns_lifetime_batch(
     pad = ((0, 0), (0, 0), (w, w), (0, 0))
     ctx_p = jnp.pad(ctx, pad)
     out_p = jnp.pad(out, pad)
-    valid_p = jnp.pad(valid.astype(jnp.int32), ((0, 0), (0, 0), (w, w)))
+    valid_p = jnp.pad(valid.astype(jnp.float32),
+                      ((0, 0), (0, 0), (w, w)))[..., None]
     lr_arr = jnp.full((1, 1), lr, jnp.float32)
     ctx_p, out_p, neg_o, loss = sgns_lifetime_pallas(
         ctx_p, out_p, neg, valid_p, lr_arr,

@@ -161,7 +161,11 @@ def oracle_topk(phi: np.ndarray, u: int, k: int):
     """NumPy reference for top-K: (values, ids), self excluded, ties
     broken toward the lower id (matching ``lax.top_k``)."""
     phi = np.asarray(phi, np.float32)
-    scores = chain_dot(phi[int(u)][None, :], phi)
+    query = phi[int(u)][None, :]
+    # Row blocks keep each block's column adds in cache; every score is
+    # still the same chain, so the result does not depend on the block.
+    scores = np.concatenate([chain_dot(query, phi[i:i + 8192])
+                             for i in range(0, len(phi), 8192)])
     scores[int(u)] = -np.inf
     ids = np.argsort(-scores, kind="stable")[:k]
     return scores[ids], ids

@@ -175,7 +175,6 @@ def test_sharded_train_step_2x4_mesh():
 
 def test_compressed_allreduce_8dev():
     out = _run_subprocess("""
-        from jax.experimental.shard_map import shard_map
         from repro.dist.collectives import compressed_allreduce
         mesh = Mesh(np.asarray(jax.devices()).reshape(8), ("data",))
 
@@ -184,9 +183,9 @@ def test_compressed_allreduce_8dev():
 
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
         e = jnp.zeros((8, 64))
-        synced, resid = shard_map(
+        synced, resid = jax.shard_map(
             f, mesh=mesh, in_specs=(P("data"), P("data")),
-            out_specs=(P(), P("data")), check_rep=False)(g, e)
+            out_specs=(P(), P("data")), check_vma=False)(g, e)
         # error feedback: sparse + residual == original per shard
         print("COMPRESS_OK", float(jnp.abs(synced).sum()))
     """)

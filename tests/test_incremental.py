@@ -16,7 +16,7 @@ from repro.core.incremental import affected_roots, changed_arc_codes
 from repro.core.termination import WalkCountController
 from repro.core.transition import make_policy
 from repro.core.walker import WalkSpec, run_walk_batch
-from repro.graph.csr import build_csr, edge_common_neighbors_fast
+from repro.graph.csr import build_csr, edge_common_neighbors_ref
 from repro.graph.delta import DeltaCSR, EdgeBatch, bump_graph_version, \
     graph_version
 from repro.graph.generators import churn_batch, rmat_graph, undirected_edges
@@ -106,7 +106,7 @@ class TestDeltaOverlay:
         merged = d.graph()
         np.testing.assert_array_equal(
             np.asarray(merged.to_numpy().edge_cm),
-            edge_common_neighbors_fast(merged))
+            edge_common_neighbors_ref(merged))
 
     def test_auto_compaction_threshold(self):
         g = self._base()

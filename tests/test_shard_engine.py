@@ -109,10 +109,10 @@ def test_windowed_message_carries_ring(medium_graph):
 def test_spmd_shard_map_matches_stacked(medium_graph):
     """The shard_map execution (real per-device collectives) is
     bit-identical to the stacked vmap emulation."""
-    mesh = make_walk_mesh(4)
-    if mesh is None:
+    if len(jax.devices()) < 4:
         pytest.skip("needs >= 4 devices (e.g. "
                     "XLA_FLAGS=--xla_force_host_platform_device_count=4)")
+    mesh = make_walk_mesh(4)
     spec = WalkSpec(max_len=32, min_len=8, mu=0.995, info_mode="incom",
                     reg_start=16)
     part = mpgp_partition(medium_graph, 4, gamma=2.0).assignment
@@ -128,6 +128,22 @@ def test_spmd_shard_map_matches_stacked(medium_graph):
                                   np.asarray(st_m.info.L))
     assert int(st_v.msg_count) == int(st_m.msg_count)
     assert float(st_v.msg_bytes) == float(st_m.msg_bytes)
+
+
+def test_walk_mesh_never_falls_back_silently(small_graph):
+    """Asking for more devices than the host has raises instead of handing
+    back None (which callers would run as the stacked emulation), and a
+    mesh whose size differs from the shard count is refused."""
+    have = len(jax.devices())
+    with pytest.raises(RuntimeError, match=f"this host has {have}"):
+        make_walk_mesh(have + 1)
+    mesh = make_walk_mesh(1)
+    spec = WalkSpec(max_len=16, min_len=4, mu=0.995, info_mode="incom")
+    with pytest.raises(ValueError, match="for 2 shards"):
+        run_walk_sharded(small_graph, jnp.arange(4, dtype=jnp.int32),
+                         jax.random.PRNGKey(0), make_policy("deepwalk"),
+                         spec, jnp.zeros(small_graph.num_nodes, jnp.int32),
+                         2, mesh=mesh)
 
 
 def test_corpus_ring_append_and_ocn(small_graph):
