@@ -17,9 +17,8 @@ import time
 import traceback
 
 from benchmarks import (
-    classification, e2e, generality, incom_bench, incremental, obs_overhead,
-    partitioning, recovery, scaling, serve, sync_bytes, train_efficiency,
-    walk_efficiency,
+    classification, e2e, generality, incom_bench, incremental, partitioning,
+    recovery, scaling, serve, sync_bytes, train_efficiency, walk_efficiency,
 )
 
 BENCHES = {
@@ -34,7 +33,6 @@ BENCHES = {
     "classification": classification.run,     # Fig. 9
     "incremental": incremental.run,           # dynamic-graph refresh (PR 4)
     "recovery": recovery.run,                 # fault-tolerance MTTR (PR 6)
-    "obs_overhead": obs_overhead.run,         # telemetry tax (DESIGN.md §13)
     "serve": serve.run,                       # embedding read path (PR 10)
 }
 
@@ -301,43 +299,6 @@ def _emit_bench_recovery(rec: dict) -> None:
     print(f"wrote {path}", flush=True)
 
 
-def _emit_bench_obs(rec: dict) -> None:
-    """Repo-root BENCH_obs.json + RUN_TELEMETRY.json: the telemetry tax
-    (best-of-reps pipeline wall with the substrate fully on vs fully off,
-    plus the gated no-op cost) and the per-run telemetry export from the
-    same telemetry-on run — both uploaded by the CI bench-artifacts job."""
-    bench = {
-        "workload": {
-            "nodes": rec.get("nodes"),
-            "dim": rec.get("dim"),
-            "reps": rec.get("reps"),
-        },
-        "overhead": {
-            "wall_on_s": rec.get("wall_on_s"),
-            "wall_off_s": rec.get("wall_off_s"),
-            "overhead_pct": rec.get("overhead_pct"),
-            "noop_ns_per_call": rec.get("noop_ns_per_call"),
-            "spans_recorded": rec.get("spans_recorded"),
-        },
-        # ISSUE 9 acceptance tracker: hot-loop telemetry tax under 3%.
-        "acceptance": {
-            "overhead_lt_3pct": bool(rec.get("overhead_pct", 100.0) < 3.0),
-        },
-    }
-    path = os.path.join(REPO_ROOT, "BENCH_obs.json")
-    with open(path, "w") as f:
-        json.dump(bench, f, indent=1, default=float)
-    print(f"wrote {path}", flush=True)
-    telemetry = rec.get("telemetry")
-    if telemetry:
-        from repro.obs.export import SCHEMA
-        tpath = os.path.join(REPO_ROOT, "RUN_TELEMETRY.json")
-        assert telemetry.get("schema") == SCHEMA
-        with open(tpath, "w") as f:
-            json.dump(telemetry, f, indent=1, default=float)
-        print(f"wrote {tpath}", flush=True)
-
-
 def _emit_bench_serve(rec: dict) -> None:
     """Repo-root BENCH_serve.json: the embedding read path under chaos —
     queries/s + tail latency of the slot-pool wave scheduler, and the
@@ -425,8 +386,6 @@ def main() -> int:
                 _emit_bench_incremental(rec)
             if name == "recovery" and args.only == name:
                 _emit_bench_recovery(rec)
-            if name == "obs_overhead" and args.only == name:
-                _emit_bench_obs(rec)
             if name == "serve" and args.only == name:
                 _emit_bench_serve(rec)
         except Exception as e:

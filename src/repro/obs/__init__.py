@@ -4,8 +4,10 @@ Four pieces behind one switch:
 
 * ``metrics``  — process-wide registry of counters / gauges / bounded-
   window histograms (``inc`` / ``set_gauge`` / ``observe``);
-* ``trace``    — nested ``trace_span`` phase timing that shares fields
-  with ``common.logging.log_context``;
+* ``trace``    — ``phase`` and nested ``trace_span`` timing on the
+  profiler's clock (``TraceAnnotation`` ``repro.<name>``), spans sharing
+  fields with ``common.logging.log_context``, and the garbage-collector
+  hook;
 * ``recorder`` — bounded ring of recent spans/events, dumped to disk as
   a postmortem when a fault / divergence / retry path fails;
 * ``export``   — Prometheus text snapshot + per-run RUN_TELEMETRY.json.
@@ -13,7 +15,7 @@ Four pieces behind one switch:
 The whole substrate is host-side bookkeeping over scalars the runtime
 already pulled: telemetry on vs off is bit-identical (property-tested),
 and ``REPRO_TELEMETRY=0`` / ``configure(enabled=False)`` turns every
-entry point into a flag check.
+entry point into a flag check and removes the collector hook.
 """
 
 from __future__ import annotations
@@ -43,6 +45,7 @@ from repro.obs.recorder import (  # noqa: F401
 from repro.obs.trace import (  # noqa: F401
     ambient_fields,
     current_span,
+    phase,
     span_event,
     span_stack,
     trace_span,
@@ -55,6 +58,9 @@ from repro.obs.export import (  # noqa: F401
 
 from repro.obs import config as _config
 from repro.obs import recorder as _recorder
+from repro.obs import trace as _trace
+
+_trace.sync_collector_hook()
 
 
 @contextlib.contextmanager

@@ -3,11 +3,11 @@
 One mutable configuration shared by the registry, the tracer and the
 flight recorder, so a single ``configure(enabled=False)`` (or
 ``REPRO_TELEMETRY=0`` in the environment) turns the WHOLE substrate into
-cheap no-ops. The zero-numerical-footprint contract of the subsystem
-(DESIGN.md §13) is enforced structurally — telemetry only ever records
-host-side scalars that the runtime already computed — but the off switch
-additionally buys back the (small) host bookkeeping cost, and the
-``obs_overhead`` benchmark measures exactly that on/off delta.
+cheap no-ops and removes the garbage-collector hook. The
+zero-numerical-footprint contract of the subsystem (DESIGN.md §13) is
+enforced structurally — telemetry only ever records host-side scalars
+that the runtime already computed — but the off switch additionally buys
+back the (small) host bookkeeping cost.
 
 Sinks:
 
@@ -64,6 +64,8 @@ def configure(*, enabled: Optional[bool] = None,
             _STATE["jsonl_path"] = jsonl_path
         if flight_dir is not None:
             _STATE["flight_dir"] = flight_dir
+        from repro.obs import trace
+        trace.sync_collector_hook()
     return prev
 
 

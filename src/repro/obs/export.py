@@ -1,10 +1,9 @@
 """Run-telemetry export: the per-run ``RUN_TELEMETRY.json`` summary.
 
 One JSON document per run — the metrics snapshot plus run identity —
-written at the end of a streaming run or a bench, consumed by
-``benchmarks/run.py`` (the ``obs_overhead`` row embeds one) and uploaded
-by the CI ``bench-artifacts`` job. The schema is deliberately flat and
-versioned so CI-side consumers can assert on it without importing repro.
+written by ``write_run_telemetry`` at the end of a run. The schema is
+deliberately flat and versioned so consumers can assert on it without
+importing repro.
 """
 
 from __future__ import annotations

@@ -28,7 +28,7 @@ from __future__ import annotations
 import collections
 import math
 import threading
-from typing import Any, Dict, Iterable, Optional
+from typing import Any, Dict, Iterable, List, Optional
 
 import numpy as np
 
@@ -54,6 +54,9 @@ class Reservoir:
 
     def add(self, value: float) -> None:
         self._values.append(float(value))
+
+    def extend(self, values: Iterable[float]) -> None:
+        self._values.extend(values)
 
     def values(self) -> np.ndarray:
         return np.asarray(self._values, np.float64)
@@ -116,6 +119,20 @@ class Histogram:
             self.max = v
         self.reservoir.add(v)
 
+    def observe_many(self, values: List[float]) -> None:
+        """``observe`` each of a list of floats, in one call (per-read
+        sites)."""
+        if not values:
+            return
+        self.count += len(values)
+        self.sum += sum(values)
+        lo, hi = min(values), max(values)
+        if lo < self.min:
+            self.min = lo
+        if hi > self.max:
+            self.max = hi
+        self.reservoir.extend(values)
+
     def values(self) -> np.ndarray:
         """Window contents (the percentile substrate)."""
         return self.reservoir.values()
@@ -138,13 +155,40 @@ class Histogram:
         }
 
 
+class CollectorTotals:
+    """Collections and pause seconds of Python's garbage collector, by
+    generation. The ``gc.callbacks`` hook (``obs.trace``) adds to these
+    plain lists and takes no lock: a collection can start while its own
+    thread holds any of the telemetry locks. The registry folds them into
+    its counters when it snapshots."""
+
+    __slots__ = ("collections", "pause_s")
+
+    def __init__(self):
+        self.collections = [0, 0, 0]
+        self.pause_s = [0.0, 0.0, 0.0]
+
+    def counters(self) -> Dict[str, float]:
+        out: Dict[str, float] = {}
+        for g, n in enumerate(self.collections):
+            if n:
+                out[f"gc.collections.gen{g}"] = float(n)
+                out[f"gc.pause_s.gen{g}"] = self.pause_s[g]
+        return out
+
+    def reset(self) -> None:
+        self.collections[:] = [0, 0, 0]
+        self.pause_s[:] = [0.0, 0.0, 0.0]
+
+
 class MetricsRegistry:
     """Name → metric map with get-or-create accessors.
 
     ``attach`` registers an externally-owned metric object (the ingest
     driver owns its latency histogram — its window must follow the
     driver's config, and a fresh driver must not inherit a dead one's
-    samples — but the registry still exports it).
+    samples — but the registry still exports it). An existing metric is
+    found without the lock; only creating one takes it.
     """
 
     def __init__(self):
@@ -152,9 +196,13 @@ class MetricsRegistry:
         self._counters: Dict[str, Counter] = {}
         self._gauges: Dict[str, Gauge] = {}
         self._histograms: Dict[str, Histogram] = {}
+        self.gc = CollectorTotals()
 
     # -- get-or-create -----------------------------------------------------
     def counter(self, name: str) -> Counter:
+        m = self._counters.get(name)
+        if m is not None:
+            return m
         with self._lock:
             m = self._counters.get(name)
             if m is None:
@@ -162,6 +210,9 @@ class MetricsRegistry:
             return m
 
     def gauge(self, name: str) -> Gauge:
+        m = self._gauges.get(name)
+        if m is not None:
+            return m
         with self._lock:
             m = self._gauges.get(name)
             if m is None:
@@ -169,6 +220,9 @@ class MetricsRegistry:
             return m
 
     def histogram(self, name: str, window: int = DEFAULT_WINDOW) -> Histogram:
+        m = self._histograms.get(name)
+        if m is not None:
+            return m
         with self._lock:
             m = self._histograms.get(name)
             if m is None:
@@ -187,12 +241,15 @@ class MetricsRegistry:
             self._counters.clear()
             self._gauges.clear()
             self._histograms.clear()
+            self.gc.reset()
 
     # -- export ------------------------------------------------------------
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
             return {
-                "counters": {n: c.value for n, c in self._counters.items()},
+                "counters": {**{n: c.value
+                                for n, c in self._counters.items()},
+                             **self.gc.counters()},
                 "gauges": {n: g.value for n, g in self._gauges.items()
                            if g.value is not None},
                 "histograms": {n: h.summary()

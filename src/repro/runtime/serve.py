@@ -202,6 +202,7 @@ class Response:
     staleness_s: float
     freshness: str              # "fresh" | "stale"
     latency_s: float
+    wave: int                   # id of the wave that answered it
 
 
 @dataclasses.dataclass
@@ -251,6 +252,7 @@ class EmbedServer:
         self._queue: Deque[Query] = deque()
         self._active: Optional[EmbedSnapshot] = None
         self._next_qid = 0
+        self._next_wave = 0
         self._wave_ema: Optional[float] = None
         self._newer_pending = False     # a newer candidate exists but was
                                         # rejected (torn / unhealthy)
@@ -410,50 +412,77 @@ class EmbedServer:
     def tick(self) -> List[Response]:
         """Score one wave from the queue on the snapshot captured at wave
         formation. On a wave fault the wave is re-queued at the front and
-        the failure propagates — admitted queries survive the crash."""
-        with self._lock:
-            if not self._queue:
-                return []
-            take = min(len(self._queue), max(self.cfg.batch_slots, 1))
-            wave = [self._queue.popleft() for _ in range(take)]
-            snap = self._active
-            freshness = self._freshness_locked()
-        t0 = self.clock()
-        try:
-            self.faults.fire("serve_wave", note=len(wave))
-            with obs.trace_span("serve.wave", size=len(wave),
-                                version=snap.version):
+        the failure propagates — admitted queries survive the crash.
+
+        The wave runs inside the phase ``serve.tick``, tiled by its
+        children ``serve.form``, ``serve.group``, ``serve.dispatch``,
+        ``serve.fetch`` and ``serve.respond`` (DESIGN.md §13)."""
+        if not self._queue:
+            return []
+        with obs.phase("serve.tick"):
+            with obs.phase("serve.form"):
+                with self._lock:
+                    if not self._queue:
+                        return []
+                    take = min(len(self._queue),
+                               max(self.cfg.batch_slots, 1))
+                    wave = [self._queue.popleft() for _ in range(take)]
+                    snap = self._active
+                    freshness = self._freshness_locked()
+                    wave_id = self._next_wave
+                    self._next_wave += 1
+                t0 = self.clock()
+                if obs.enabled():
+                    obs.REGISTRY.histogram("serve.queue_wait_s").observe_many(
+                        [t0 - q.submit_t for q in wave])
+            try:
+                self.faults.fire("serve_wave", note=len(wave))
                 scored = self._score_wave(wave, snap)
-        except Exception:
-            with self._lock:
-                self._queue.extendleft(reversed(wave))
-            self.wave_faults += 1
-            obs.inc("serve.wave_faults")
-            raise
-        now = self.clock()
-        wall = now - t0
+            except Exception:
+                with self._lock:
+                    self._queue.extendleft(reversed(wave))
+                self.wave_faults += 1
+                obs.inc("serve.wave_faults")
+                raise
+            now = self.clock()
+            with obs.phase("serve.respond"):
+                return self._respond(wave, wave_id, scored, snap,
+                                     freshness, now - t0, now)
+
+    def _respond(self, wave: List[Query], wave_id: int, scored: list,
+                 snap: EmbedSnapshot, freshness: str, wall: float,
+                 now: float) -> List[Response]:
+        """Slice each read's answer out of its group's host arrays, stamp
+        the responses and account for them."""
         with self._lock:
             b = self.cfg.ema_beta
             self._wave_ema = (wall if self._wave_ema is None
                               else b * self._wave_ema + (1 - b) * wall)
+        answers: Dict[int, tuple] = {}
+        for group, ids, scores in scored:
+            for i, q in enumerate(group):
+                answers[q.qid] = ((ids[i], scores[i]) if ids is not None
+                                  else (q.candidates,
+                                        scores[i, :len(q.candidates)]))
+        staleness = max(now - snap.created_t, 0.0)
         out = []
-        for q, (ids, scores) in zip(wave, scored):
+        for q in wave:
+            ids, scores = answers[q.qid]
             resp = Response(
                 qid=q.qid, u=q.u, ids=ids, scores=scores,
                 served_version=snap.version,
                 served_graph_version=snap.graph_version,
-                staleness_s=max(now - snap.created_t, 0.0),
-                freshness=freshness, latency_s=now - q.submit_t)
+                staleness_s=staleness, freshness=freshness,
+                latency_s=now - q.submit_t, wave=wave_id)
             self.responses[q.qid] = resp
             out.append(resp)
-            self.served += 1
-            self.served_by_version[snap.version] = \
-                self.served_by_version.get(snap.version, 0) + 1
-            self.served_by_freshness[freshness] += 1
             self._latency.observe(resp.latency_s)
+        self.served += len(out)
+        self.served_by_version[snap.version] = \
+            self.served_by_version.get(snap.version, 0) + len(out)
+        self.served_by_freshness[freshness] += len(out)
         obs.inc("serve.responses", len(out))
-        obs.set_gauge("serve.staleness_s",
-                      max(now - snap.created_t, 0.0))
+        obs.set_gauge("serve.staleness_s", staleness)
         return out
 
     def _freshness_locked(self) -> str:
@@ -461,37 +490,41 @@ class EmbedServer:
                 and not self._newer_pending else "stale")
 
     def _score_wave(self, wave: List[Query], snap: EmbedSnapshot) -> list:
-        """Batched device scoring of one wave. Top-K queries group by k,
-        pair queries by a padded candidate bucket (powers of two, to
-        bound recompiles); padding never leaks — per-query slices are
-        trimmed before the response."""
-        results: Dict[int, tuple] = {}
-        topk_groups: Dict[int, List[Query]] = {}
-        cand_groups: Dict[int, List[Query]] = {}
-        for q in wave:
-            if q.candidates is None:
-                topk_groups.setdefault(q.k, []).append(q)
-            else:
-                width = max(1, 1 << (len(q.candidates) - 1).bit_length()) \
-                    if len(q.candidates) else 1
-                cand_groups.setdefault(width, []).append(q)
-        for k, group in topk_groups.items():
-            u = jnp.asarray([q.u for q in group], jnp.int32)
-            vals, ids = _topk(snap.phi, u, k)
-            vals, ids = np.asarray(vals), np.asarray(ids)
-            for i, q in enumerate(group):
-                results[q.qid] = (ids[i], vals[i])
-        for width, group in cand_groups.items():
-            cand = np.zeros((len(group), width), np.int32)
-            for i, q in enumerate(group):
-                cand[i, :len(q.candidates)] = q.candidates
-            u = jnp.asarray([q.u for q in group], jnp.int32)
-            scores = np.asarray(
-                _score_candidates(snap.phi, u, jnp.asarray(cand)))
-            for i, q in enumerate(group):
-                n = len(q.candidates)
-                results[q.qid] = (np.asarray(q.candidates), scores[i, :n])
-        return [results[q.qid] for q in wave]
+        """Batched device scoring of one wave: ``[(group, ids, scores)]``
+        in host arrays, ``ids`` None for a pair group (its reads' own
+        candidates). Top-K queries group by k, pair queries by a padded
+        candidate bucket (powers of two, to bound recompiles); padding
+        never leaks — ``_respond`` trims each read's slice. Every group is
+        dispatched before the first is fetched."""
+        with obs.phase("serve.group"):
+            topk_groups: Dict[int, List[Query]] = {}
+            cand_groups: Dict[int, List[Query]] = {}
+            for q in wave:
+                if q.candidates is None:
+                    topk_groups.setdefault(q.k, []).append(q)
+                else:
+                    width = max(1, 1 << (len(q.candidates) - 1)
+                                .bit_length()) if len(q.candidates) else 1
+                    cand_groups.setdefault(width, []).append(q)
+            padded = []
+            for width, group in cand_groups.items():
+                cand = np.zeros((len(group), width), np.int32)
+                for i, q in enumerate(group):
+                    cand[i, :len(q.candidates)] = q.candidates
+                padded.append((group, cand))
+        with obs.phase("serve.dispatch"):
+            pending = []
+            for k, group in topk_groups.items():
+                u = jnp.asarray([q.u for q in group], jnp.int32)
+                vals, ids = _topk(snap.phi, u, k)
+                pending.append((group, ids, vals))
+            for group, cand in padded:
+                u = jnp.asarray([q.u for q in group], jnp.int32)
+                pending.append((group, None, _score_candidates(
+                    snap.phi, u, jnp.asarray(cand))))
+        with obs.phase("serve.fetch"):
+            return [(group, None if ids is None else np.asarray(ids),
+                     np.asarray(scores)) for group, ids, scores in pending]
 
     def drain(self) -> List[Response]:
         """Tick until the queue is empty; responses in completion order."""

@@ -255,38 +255,31 @@ class DSGLTrainer:
         t0 = time.perf_counter()
         sync_bytes = 0.0
         do_sync = self.num_shards > 1
-        # Hot loop: telemetry is a flag check when off, two clock reads +
-        # a histogram add when on (the obs_overhead bench measures this).
-        tele = obs.enabled()
         try:
             for c, (epoch, step0, count) in enumerate(schedule):
-                t_c = time.perf_counter() if tele else 0.0
                 _, chunk_np = prefetcher.next()
                 wb = jnp.asarray(chunk_np)
                 rows = (jnp.asarray(self._sync.sample_hotness_rows(
                     self.starts, self.ends, rng), jnp.int32)
                     if do_sync else jnp.zeros(0, jnp.int32))
                 self.key, sub = jax.random.split(self.key)
-                self.phi_in, self.phi_out, loss = train_chunk(
-                    self.phi_in, self.phi_out, wb, self.neg_table, rows, sub,
-                    self._lrs(epoch * spe + step0, count, total),
-                    cfg.window, cfg.negatives, cfg.use_kernel, do_sync)
+                with obs.phase("dsgl.chunk"):
+                    self.phi_in, self.phi_out, loss = train_chunk(
+                        self.phi_in, self.phi_out, wb, self.neg_table, rows,
+                        sub, self._lrs(epoch * spe + step0, count, total),
+                        cfg.window, cfg.negatives, cfg.use_kernel, do_sync)
                 losses.append(loss)
                 if do_sync:
                     sync_bytes += float(
                         rows.size * cfg.dim * 4 * self.num_shards * 2)
-                if tele:
-                    obs.observe("train.chunk_dispatch.s",
-                                time.perf_counter() - t_c)
-                    obs.inc("train.steps", count)
+                obs.inc("train.steps", count)
         finally:
             prefetcher.close()
         jax.block_until_ready(self.phi_in)
         wall = time.perf_counter() - t0
         steps = total
-        if tele:
-            obs.set_gauge("train.steps_per_s", steps / max(wall, 1e-9))
-            obs.set_gauge("train.sync_bytes", sync_bytes)
+        obs.set_gauge("train.steps_per_s", steps / max(wall, 1e-9))
+        obs.set_gauge("train.sync_bytes", sync_bytes)
         return {
             "steps": steps,
             "steps_per_s": steps / max(wall, 1e-9),
@@ -535,9 +528,7 @@ class StreamingEmbedPipeline:
             order = FrequencyOrder.from_ocn(ocn_host) if replicated else None
         chunk = max(min(cfg.sync_period, steps), 1)
         done = 0
-        tele = obs.enabled()
         while done < steps:
-            t_c = time.perf_counter() if tele else 0.0
             count = min(chunk, steps - done)
             # Improvement-III cadence: one hotness exchange per sync_period
             # LIFETIMES (global steps), not per dispatched chunk — rounds
@@ -570,22 +561,20 @@ class StreamingEmbedPipeline:
                 lrs = lrs * 1e4
             check = (self.health is not None
                      and self.health.due(self.global_step, count))
-            if check:
-                self.phi_in, self.phi_out, _, hs = train_chunk_checked(
-                    self.phi_in, self.phi_out, wb, table, rows, ck2,
-                    lrs, cfg.window, cfg.negatives,
-                    cfg.use_kernel, sync_now)
-            else:
-                self.phi_in, self.phi_out, _ = train_chunk(
-                    self.phi_in, self.phi_out, wb, table, rows, ck2,
-                    lrs, cfg.window, cfg.negatives,
-                    cfg.use_kernel, sync_now)
+            with obs.phase("dsgl.chunk"):
+                if check:
+                    self.phi_in, self.phi_out, _, hs = train_chunk_checked(
+                        self.phi_in, self.phi_out, wb, table, rows, ck2,
+                        lrs, cfg.window, cfg.negatives,
+                        cfg.use_kernel, sync_now)
+                else:
+                    self.phi_in, self.phi_out, _ = train_chunk(
+                        self.phi_in, self.phi_out, wb, table, rows, ck2,
+                        lrs, cfg.window, cfg.negatives,
+                        cfg.use_kernel, sync_now)
             self.global_step += count
             done += count
-            if tele:
-                obs.observe("train.chunk_dispatch.s",
-                            time.perf_counter() - t_c)
-                obs.inc("train.steps", count)
+            obs.inc("train.steps", count)
             if check:
                 # One host pull of 5 scalars; raises DivergenceError on a
                 # verdict — run()'s heal loop owns the reaction.

@@ -15,6 +15,8 @@ Four contracts under test:
 """
 
 import dataclasses
+import gc
+import glob
 import json
 import logging
 import os
@@ -29,8 +31,10 @@ from repro.core.api import EmbedConfig, make_walk_plan
 from repro.core.dsgl import DSGLConfig
 from repro.graph.delta import EdgeBatch
 from repro.graph.generators import rmat_graph
+from repro.obs import config as obs_config
 from repro.obs import metrics as obs_metrics
 from repro.obs import recorder as obs_recorder
+from repro.obs import trace as obs_trace
 from repro.runtime.faults import (FaultInjector, SimulatedFailure,
                                   run_with_restarts)
 from repro.runtime.health import HealthConfig, HealthMonitor
@@ -197,6 +201,96 @@ class TestTracer:
         assert [r["kind"] for r in lines] == ["event", "span"]
         assert lines[1]["name"] == "walk.round"
         assert lines[1]["fields"]["round"] == 4
+
+
+def host_events(tmp_path, body):
+    """[(name, start_ns, dur_ns)] of the ``repro.*`` host events that a CPU
+    ``jax.profiler`` trace records around ``body()``."""
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                            / "*.xplane.pb"))
+    return [(e.name, e.start_ns, e.duration_ns)
+            for plane in ProfileData.from_file(path).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith("repro.")]
+
+
+class TestProfilerClock:
+    def test_span_and_phase_on_profiler_trace(self, tmp_path):
+        def body():
+            with obs.trace_span("unit.span", round=1):
+                with obs.phase("unit.phase"):
+                    pass
+        events = {n: (s, d) for n, s, d in host_events(tmp_path, body)}
+        assert {"repro.unit.span", "repro.unit.phase"} <= set(events)
+        s0, d0 = events["repro.unit.span"]
+        s1, d1 = events["repro.unit.phase"]
+        assert s0 <= s1 and s1 + d1 <= s0 + d0
+        hist = obs.REGISTRY.snapshot()["histograms"]
+        assert hist["span.unit.span.s"]["count"] == 1
+        assert hist["span.unit.phase.s"]["count"] == 1
+
+    def test_phase_is_light(self):
+        """A phase keeps no frame, record or log context: only its
+        histogram."""
+        with obs.phase("unit.light") as ph:
+            assert obs.current_span() is None
+            assert obs.span_stack() == ()
+        assert ph.wall_s is not None and ph.wall_s >= 0.0
+        assert obs.recent() == []
+        assert obs.REGISTRY.histogram("span.unit.light.s").count == 1
+
+    def test_disabled_records_nothing_and_removes_hook(self, tmp_path):
+        def body():
+            with obs.trace_span("unit.off"):
+                with obs.phase("unit.off_phase") as ph:
+                    assert ph.wall_s is None
+            gc.collect()
+        with obs.override(enabled=False):
+            assert obs_trace._COLLECTOR_HOOK not in gc.callbacks
+            before = list(obs.REGISTRY.gc.collections)
+            events = host_events(tmp_path, body)
+            assert obs.REGISTRY.gc.collections == before
+        assert events == []
+        assert obs.REGISTRY.snapshot()["histograms"] == {}
+        assert gc.callbacks.count(obs_trace._COLLECTOR_HOOK) == 1
+
+    def test_gc_collection_is_a_phase_and_counted(self, tmp_path):
+        names = [n for n, _, _ in host_events(tmp_path, gc.collect)]
+        assert "repro.gc.collect.gen2" in names
+        counters = obs.REGISTRY.snapshot()["counters"]
+        assert counters["gc.collections.gen2"] >= 1
+        assert counters["gc.pause_s.gen2"] > 0.0
+        obs.reset()
+        assert "gc.collections.gen2" not in \
+            obs.REGISTRY.snapshot()["counters"]
+
+    def test_gc_hook_takes_no_lock(self):
+        """A collection can start while its own thread holds a telemetry
+        lock; the hook must not wait on any of them."""
+        done = threading.Event()
+        locks = (obs.REGISTRY._lock, obs_recorder._LOCK, obs_config._LOCK)
+        for lock in locks:
+            lock.acquire()
+        try:
+            t = threading.Thread(target=lambda: (gc.collect(), done.set()),
+                                 daemon=True)
+            t.start()
+            t.join(timeout=30.0)
+            assert done.is_set() and not t.is_alive()
+        finally:
+            for lock in locks:
+                lock.release()
+        assert obs.REGISTRY.snapshot()["counters"]["gc.collections.gen2"] \
+            >= 1
 
 
 # --- logging satellite ------------------------------------------------------
@@ -427,6 +521,31 @@ class TestBitIdentityOnVsOff:
         off = _run_heal(graph, tmp_path, False, "off")
         for a, b in zip(on, off):
             np.testing.assert_array_equal(a, b)
+
+    def test_serve_scores(self, tmp_path):
+        """Telemetry on and off answer the same reads with the same bits."""
+        from repro.ckpt.checkpoint import save_checkpoint
+        from repro.runtime.serve import EmbedServer, ServeConfig
+
+        phi = np.random.default_rng(5).standard_normal((64, 16)) \
+            .astype(np.float32)
+        save_checkpoint(str(tmp_path), 0, {"phi_in": phi},
+                        meta={"graph_version": 0, "global_step": 0})
+        rng = np.random.default_rng(6)
+        queries = [{"u": int(rng.integers(0, 64)),
+                    "candidates": rng.integers(0, 64, size=int(w))}
+                   for w in rng.integers(1, 20, size=12)]
+        queries += [{"u": int(u), "k": 5} for u in rng.integers(0, 64, 4)]
+        answers = []
+        for enabled in (True, False):
+            with obs.override(enabled=enabled):
+                srv = EmbedServer(ServeConfig(batch_slots=5))
+                srv.offer_snapshot(str(tmp_path))
+                answers.append(srv.serve(queries))
+        for on, off in zip(*answers):
+            np.testing.assert_array_equal(on.ids, off.ids)
+            np.testing.assert_array_equal(on.scores, off.scores)
+            assert on.wave == off.wave
 
     def test_across_resume(self, graph, tmp_path):
         """Telemetry ON for the interrupted+resumed run, OFF for the

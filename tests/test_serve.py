@@ -404,3 +404,85 @@ class TestDegradeLadderAndAdmission:
         assert s["served_by_freshness"]["fresh"] == 2
         assert s["latency_p50_s"] >= 0.0
         assert s["offered_total"] == s["admitted"] + s["shed_total"]
+
+
+# ---------------------------------------------------------------------------
+# The wave's phases and the flight-recorder ring
+# ---------------------------------------------------------------------------
+
+
+class TestWavePhases:
+    CHILDREN = ("serve.form", "serve.group", "serve.dispatch",
+                "serve.fetch", "serve.respond")
+
+    def test_children_nest_in_tick_and_responses_carry_wave(self, tmp_path):
+        import glob
+
+        import jax
+        from jax.profiler import ProfileData
+
+        phi = _phi(seed=21)
+        _ckpt(tmp_path / "snap", 0, phi)
+        srv = _server(cfg=ServeConfig(batch_slots=4))
+        srv.offer_snapshot(str(tmp_path / "snap"))
+        qids = [srv.submit(u, candidates=[1, 2, 3]) for u in range(6)]
+        qids.append(srv.submit(7, k=3))
+        log_dir = tmp_path / "trace"
+        jax.profiler.start_trace(str(log_dir))
+        try:
+            srv.drain()
+        finally:
+            jax.profiler.stop_trace()
+        (path,) = glob.glob(str(log_dir / "plugins" / "profile" / "*"
+                                / "*.xplane.pb"))
+        events = [(e.name[len("repro."):], e.start_ns,
+                   e.start_ns + e.duration_ns)
+                  for plane in ProfileData.from_file(path).planes
+                  if plane.name.startswith("/host:")
+                  for line in plane.lines for e in line.events
+                  if e.name.startswith("repro.serve.")]
+        ticks = [(s, e) for n, s, e in events if n == "serve.tick"]
+        assert len(ticks) == 2
+        for lo, hi in ticks:
+            inside = sorted((s, e, n) for n, s, e in events
+                            if lo <= s and e <= hi and n != "serve.tick")
+            assert [n for _, _, n in inside] == list(self.CHILDREN)
+            for (_, end, _), (start, _, _) in zip(inside, inside[1:]):
+                assert end <= start
+        waves = [srv.responses[q].wave for q in qids]
+        assert waves == [0, 0, 0, 0, 1, 1, 1]
+        for q, u in zip(qids[:6], range(6)):
+            assert np.array_equal(srv.responses[q].scores,
+                                  oracle_scores(phi, u, [1, 2, 3]))
+
+    def test_queue_wait_per_read(self, tmp_path):
+        from repro import obs
+
+        clock = FakeClock()
+        _ckpt(tmp_path, 0, _phi())
+        srv = _server(clock=clock)
+        srv.offer_snapshot(str(tmp_path))
+        obs.reset()
+        srv.submit(1, candidates=[2])
+        clock.advance(0.5)
+        srv.submit(2, candidates=[3])
+        clock.advance(0.25)
+        srv.tick()
+        waits = obs.REGISTRY.histogram("serve.queue_wait_s")
+        assert waits.count == 2
+        assert sorted(waits.values()) == pytest.approx([0.25, 0.75])
+
+    def test_ring_keeps_offer_after_many_ticks(self, tmp_path):
+        """Waves leave no record in the flight recorder, so a thousand of
+        them do not push out the snapshot offer a postmortem needs."""
+        from repro import obs
+
+        obs.reset()
+        _ckpt(tmp_path, 0, _phi())
+        srv = _server()
+        srv.offer_snapshot(str(tmp_path))
+        for i in range(1000):
+            srv.submit(i % 64, candidates=[1, 2])
+            srv.tick()
+        assert srv.served == 1000
+        assert "serve.offer" in [r["name"] for r in obs.recent()]
