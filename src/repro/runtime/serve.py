@@ -43,7 +43,9 @@ index order (XLA does not reassociate float adds) and product /
 accumulation run as separate executables (so LLVM cannot contract
 mul+add into an FMA), making device scores bit-identical to the NumPy
 oracle (``oracle_scores`` / ``oracle_topk``) — the serving path is
-testable against ground truth at the bit level.
+testable against ground truth at the bit level. Top-K builds its product
+d-major, (d, B, |V|), from a (d, |V|) copy of phi that each snapshot
+makes at its first top-K wave, and sums it plane by plane.
 """
 
 from __future__ import annotations
@@ -112,21 +114,46 @@ def _pair_products_jit(phi: jax.Array, u: jax.Array,
     return phi[u][:, None, :] * phi[cand]
 
 
-@jax.jit
-def _all_products_jit(phi: jax.Array, u: jax.Array) -> jax.Array:
-    """(B,) query nodes → (B, N, d) products against every vertex. The
-    materialized product tensor is the price of exact reproducibility;
-    an approximate fast path would use a matmul here."""
-    return phi[u][:, None, :] * phi[None, :, :]
+_LANES = 128          # TPU vector lanes: the minor dim of a (8, 128) tile
+_FUSED_PLANES = 32    # planes of the top-K add chain per fused device pass
 
 
 @jax.jit
-def _accumulate_jit(prod: jax.Array) -> jax.Array:
-    """Left-to-right add chain over the last axis — adds only, so FMA
-    contraction cannot perturb the result (see ``chain_dot``)."""
-    acc = prod[..., 0]
-    for j in range(1, prod.shape[-1]):
-        acc = acc + prod[..., j]
+def _phi_t_jit(phi: jax.Array) -> jax.Array:
+    """(N, d) → (d, N') with N' = N rounded up to whole lanes, zero past N.
+    The TPU keeps a (d, N) array with N off the lane width d-minor (it
+    chooses the layout with no padding), so N is padded to make the copy,
+    and every product built from it, row-major."""
+    return jnp.pad(phi, ((0, -phi.shape[0] % _LANES), (0, 0))).T
+
+
+@jax.jit
+def _all_products_jit(phi: jax.Array, phi_t: jax.Array,
+                      u: jax.Array) -> jax.Array:
+    """(B,) query nodes → (d, B, N') products against every vertex of the
+    d-major ``phi_t``: plane j is a contiguous (B, N') array of whole
+    tiles. The query rows come from ``phi``: gathering columns of
+    ``phi_t`` makes XLA:TPU transpose all of it first. The materialized
+    product tensor is the price of exact reproducibility; an approximate
+    fast path would use a matmul here."""
+    return phi[u].T[:, :, None] * phi_t[:, None, :]
+
+
+@functools.partial(jax.jit, static_argnames=("axis",))
+def _accumulate_jit(prod: jax.Array, axis: int = -1) -> jax.Array:
+    """Left-to-right add chain over ``axis`` — adds only, so FMA
+    contraction cannot perturb the result (see ``chain_dot``). Pair
+    scoring sums the last axis; top-K sums axis 0 of its d-major product,
+    whose planes are whole tiles. There a barrier every ``_FUSED_PLANES``
+    planes keeps each stretch of the chain one fusion that reads its
+    planes in place: unbounded, XLA:TPU copies most planes out first."""
+    whole_planes = axis % prod.ndim < prod.ndim - 2
+    lead = (slice(None),) * (axis % prod.ndim)
+    acc = prod[lead + (0,)]
+    for j in range(1, prod.shape[axis]):
+        acc = acc + prod[lead + (j,)]
+        if whole_planes and j % _FUSED_PLANES == _FUSED_PLANES - 1:
+            acc = jax.lax.optimization_barrier(acc)
     return acc
 
 
@@ -136,17 +163,20 @@ def _score_candidates(phi: jax.Array, u: jax.Array,
     return _accumulate_jit(_pair_products_jit(phi, u, cand))
 
 
-@functools.partial(jax.jit, static_argnames=("k",))
-def _topk_from_scores_jit(scores: jax.Array, u: jax.Array, k: int):
-    """(B, N) scores → (values, ids) of the k best, self excluded."""
-    scores = scores.at[jnp.arange(u.shape[0]), u].set(-jnp.inf)
+@functools.partial(jax.jit, static_argnames=("k", "n"))
+def _topk_from_scores_jit(scores: jax.Array, u: jax.Array, k: int, n: int):
+    """(B, N') scores → (values, ids) of the k best of the first n
+    vertices, self excluded."""
+    scores = scores[:, :n].at[jnp.arange(u.shape[0]), u].set(-jnp.inf)
     return jax.lax.top_k(scores, k)
 
 
-def _topk(phi: jax.Array, u: jax.Array, k: int):
-    """(B,) query nodes → (values, ids) of the k best vertices."""
-    return _topk_from_scores_jit(
-        _accumulate_jit(_all_products_jit(phi, u)), u, k)
+def _topk(snap: EmbedSnapshot, u: jax.Array, k: int):
+    """(B,) query nodes → (values, ids) of the snapshot's k best vertices,
+    scored on its d-major copy of phi."""
+    scores = _accumulate_jit(_all_products_jit(snap.phi, snap.phi_t(), u),
+                             axis=0)
+    return _topk_from_scores_jit(scores, u, k, n=snap.phi.shape[0])
 
 
 def oracle_scores(phi: np.ndarray, u: int,
@@ -216,6 +246,20 @@ class EmbedSnapshot:
     graph_version: int
     global_step: int
     created_t: float            # server clock at swap commit
+    _phi_t: Optional[jax.Array] = dataclasses.field(
+        default=None, repr=False, compare=False)
+    _phi_t_lock: threading.Lock = dataclasses.field(
+        default_factory=threading.Lock, repr=False, compare=False)
+
+    def phi_t(self) -> jax.Array:
+        """The d-major copy of ``phi`` that top-K scores on (see
+        ``_phi_t_jit``), made at the first top-K wave that reads this
+        version: a server that answers only pair reads holds none."""
+        with self._phi_t_lock:
+            if self._phi_t is None:
+                self._phi_t = _phi_t_jit(self.phi)
+                obs.inc("serve.topk_phi_t_builds")
+            return self._phi_t
 
 
 @dataclasses.dataclass
@@ -516,7 +560,7 @@ class EmbedServer:
             pending = []
             for k, group in topk_groups.items():
                 u = jnp.asarray([q.u for q in group], jnp.int32)
-                vals, ids = _topk(snap.phi, u, k)
+                vals, ids = _topk(snap, u, k)
                 pending.append((group, ids, vals))
             for group, cand in padded:
                 u = jnp.asarray([q.u for q in group], jnp.int32)

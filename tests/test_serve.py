@@ -22,6 +22,7 @@ import threading
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.ckpt.checkpoint import save_checkpoint
 from repro.runtime.faults import FaultInjector, SimulatedFailure
 from repro.runtime.health import SnapshotGate, SnapshotGateConfig
@@ -79,6 +80,7 @@ class TestBitIdentity:
             want = oracle_scores(phi, r.u, cand)
             assert np.array_equal(r.scores, want), (d, width)
             assert np.array_equal(r.ids, cand)
+        assert srv._active._phi_t is None      # pair reads build no copy
 
     @pytest.mark.parametrize("k", [1, 5, 16])
     def test_topk_matches_oracle_exactly(self, tmp_path, k):
@@ -94,6 +96,40 @@ class TestBitIdentity:
             assert np.array_equal(r.scores, vals)
             assert np.array_equal(r.ids, ids)
             assert u not in r.ids          # self excluded
+
+    @pytest.mark.parametrize("n,d,wave,swap", [
+        *[(n, d, wave, False) for n in (1000, 4099) for d in (16, 128)
+          for wave in (1, 3, 8)],
+        (1000, 16, 3, True)])
+    def test_topk_waves_match_oracle_exactly(self, tmp_path, n, d, wave,
+                                             swap):
+        """Top-K waves over |V| off the lane and sublane widths, scored on
+        the snapshot's d-major copy of phi, against the oracle bit for bit.
+        With ``swap``, a second version goes live between two waves: its
+        answers come from its own copy, and each version builds one."""
+        phis = [_phi(n, d, seed=n + d + v) for v in range(1 + swap)]
+        rng = np.random.default_rng(wave)
+        builds = obs.REGISTRY.counter("serve.topk_phi_t_builds")
+        with obs.override(enabled=True):
+            before = builds.value
+            srv = _server()
+            for version, phi in enumerate(phis):
+                _ckpt(tmp_path, version, phi)
+                assert srv.offer_snapshot(str(tmp_path))
+                users = rng.choice(n, size=wave, replace=False)
+                qids = [srv.submit(int(u), k=10) for u in users]
+                assert len(srv.tick()) == wave
+                for qid, u in zip(qids, users):
+                    r = srv.responses[qid]
+                    vals, ids = oracle_topk(phi, u, 10)
+                    assert r.served_version == version
+                    assert np.array_equal(r.scores, vals), (version, u)
+                    assert np.array_equal(r.ids, ids), (version, u)
+                phi_t = np.asarray(srv._active.phi_t())
+                assert phi_t.shape == (d, -(-n // 128) * 128)
+                assert np.array_equal(phi_t[:, :n], phi.T)
+                assert not phi_t[:, n:].any()
+            assert builds.value - before == len(phis)
 
     def test_mixed_wave_groups_do_not_leak_padding(self, tmp_path):
         """One wave mixing top-K and several candidate widths: each

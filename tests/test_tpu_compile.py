@@ -75,15 +75,28 @@ def test_train_chunk_compiles_at_yt_sim(spec, use_kernel, monkeypatch):
 
 
 def test_serve_topk_wave_compiles_at_yt_sim(spec):
-    """The top-K wave at ServeConfig(batch_slots=8): the (B, |V|, d) f32
-    product it materialises is 4.7 GB and has to fit one chip."""
+    """The top-K wave at ServeConfig(batch_slots=8): the (d, B, |V|) f32
+    product it materialises is 4.7 GB and has to fit one chip. |V| padded
+    to whole lanes makes the product row-major, so each plane the add
+    chain reads is whole tiles; the chain reads them in place and holds no
+    multiply to contract."""
     b, k = 8, 10
-    phi, u = spec((N_YT, DIM)), spec((b,), jnp.int32)
+    n_pad = -(-N_YT // 128) * 128
+    phi, phi_t = spec((N_YT, DIM)), spec((DIM, n_pad))
+    u = spec((b,), jnp.int32)
     stages = [
-        serve._all_products_jit.lower(phi, u).compile(),
-        serve._accumulate_jit.lower(spec((b, N_YT, DIM))).compile(),
-        serve._topk_from_scores_jit.lower(spec((b, N_YT)), u, k=k).compile(),
+        serve._all_products_jit.lower(phi, phi_t, u).compile(),
+        serve._accumulate_jit.lower(spec((DIM, b, n_pad)), axis=0).compile(),
+        serve._topk_from_scores_jit.lower(spec((b, n_pad)), u, k=k,
+                                          n=N_YT).compile(),
     ]
     for compiled in stages:
         assert _device_bytes(compiled) < HBM_BYTES
-    assert _device_bytes(stages[0]) >= b * N_YT * DIM * 4
+    assert _device_bytes(stages[0]) >= b * n_pad * DIM * 4
+    product = stages[0].as_text()
+    assert f"f32[{DIM},{b},{n_pad}]{{2,1,0:" in product
+    assert f"f32[{DIM},{n_pad}]{{0,1:" not in product    # no transpose
+    assert "multiply" not in stages[1].as_text()
+    assert stages[1].memory_analysis().temp_size_in_bytes <= b * n_pad * 4
+    assert f"f32[{DIM},{n_pad}]{{1,0:" in \
+        serve._phi_t_jit.lower(phi).compile().as_text()
