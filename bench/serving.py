@@ -65,14 +65,6 @@ class Users:
         return self.ids[np.minimum(ranks, len(self.ids) - 1)]
 
 
-def sample(rng: np.random.Generator, items: List, count: int) -> List:
-    """Up to ``count`` of ``items``, drawn without replacement."""
-    if len(items) <= count:
-        return list(items)
-    pick = rng.choice(len(items), size=count, replace=False)
-    return [items[i] for i in sorted(pick)]
-
-
 def check_topk(phi: np.ndarray, answers: List[Tuple[int, np.ndarray,
                                                      np.ndarray]],
                k: int, dtype=np.float32) -> Dict[str, int]:
